@@ -23,8 +23,6 @@ import shutil
 import sys
 import tempfile
 import time
-
-import repro  # noqa: F401  (applies the jaxcompat shim before jax imports)
 import jax
 import jax.numpy as jnp
 import numpy as np

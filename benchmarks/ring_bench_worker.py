@@ -11,8 +11,6 @@ import warnings
 
 warnings.filterwarnings("ignore")
 import time
-
-import repro  # noqa: F401  (jaxcompat shim before jax.sharding imports)
 import jax
 import jax.numpy as jnp
 from jax.sharding import AxisType, PartitionSpec as P

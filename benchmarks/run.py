@@ -482,7 +482,6 @@ PP_WORKER = r'''
 import os, json, time
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import warnings; warnings.filterwarnings("ignore")
-import repro  # applies the jaxcompat shim before jax imports
 import jax, jax.numpy as jnp
 from repro.core import GradSyncConfig
 from repro.data import TokenPipeline

@@ -39,7 +39,6 @@ _COLL = r"(?:all-reduce|reduce-scatter|all-gather)"
 def analyze(strategy: str, zero1: str = "") -> dict:
     """One row: ``zero1`` is "" (plain sync), "scheduled" (StepProgram)
     or "deferred" (phase-split StepProgram — the AGs tagged PRE)."""
-    import repro  # noqa: F401  (jaxcompat before jax.sharding imports)
     import jax
     import jax.numpy as jnp
     from jax.sharding import AxisType

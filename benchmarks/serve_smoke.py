@@ -30,8 +30,6 @@ import json
 import queue as queue_mod
 import sys
 import time
-
-import repro  # noqa: F401  (applies the jaxcompat shim before jax imports)
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -22,8 +22,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import tempfile
-
-import repro  # noqa: F401  (applies the jaxcompat shim before jax imports)
 import jax
 import jax.numpy as jnp
 import numpy as np

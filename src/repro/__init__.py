@@ -1,8 +1,1 @@
-"""repro: collective embedding in training DAGs (see DESIGN.md).
-
-Importing the package applies ``repro.utils.jaxcompat`` so the new-style
-jax API used throughout works on the container's older jax pin.
-"""
-from repro.utils import jaxcompat as _jaxcompat
-
-_jaxcompat.apply()
+"""repro: collective embedding in training DAGs (see DESIGN.md)."""

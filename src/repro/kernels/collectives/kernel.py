@@ -13,24 +13,15 @@ This module owns both at the kernel level:
                       reduce-scatter (received shard += local chunk, in
                       the comm dtype), chunked to VREG-aligned blocks so
                       large buckets stream through VMEM.
-  ring RS / AG      — inter-chip rings over ``make_async_remote_copy``
-                      with two VMEM message slots (hop ``s``'s slot is
-                      never overwritten by hop ``s+1``'s incoming copy).
-                      Hops are issued conservatively (start → wait →
-                      combine; splitting the send/recv waits to overlap
-                      the accumulate is the marked real-TPU bring-up
-                      refinement).  TPU-only: the transport needs real
-                      ICI; every other backend (and interpret mode) runs
-                      the ``ppermute``-based ref rings in
-                      ``repro.kernels.collectives.ref`` — whose
-                      ``bidirectional`` halves ARE the double-buffered
-                      two-messages-in-flight path — and XLA lowers each
-                      hop to the same ICI DMAs.
 
-All kernels are interpret-mode verifiable (tests/test_collectives.py)
-except the RDMA rings, which require real neighbors; their algorithm is
-covered by the ref rings' equivalence tests against
-``psum_scatter``/``all_gather`` on the 8-fake-device mesh.
+The ring transport itself is the ``ppermute``-based rings in
+``repro.kernels.collectives.ref``: XLA lowers each hop to an ICI DMA.
+
+pack / unpack hold the whole bucket in VMEM and write unaligned 1-D
+slices, which the TPU compiler refuses at real bucket sizes; they are
+reached only through an explicit ``impl="kernel"`` and are verified in
+interpret mode (tests/test_collectives.py).  ``ring_accum_kernel``
+compiles for the chip (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -39,15 +30,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only transport (Mosaic RDMA); absent on some backends
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-# renamed across jax versions (TPUCompilerParams → CompilerParams)
-_COMPILER_PARAMS = (getattr(pltpu, "CompilerParams", None)
-                    or getattr(pltpu, "TPUCompilerParams", None))
 
 # VREG-aligned block (8 sublanes × 128 lanes) for chunked ring grids
 RING_CHUNK = 8 * 128
@@ -137,106 +119,3 @@ def ring_accum_kernel(msg: jax.Array, chunk: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((n,), msg.dtype),
         interpret=interpret,
     )(msg, chunk)
-
-
-# ------------------------------------------- RDMA rings (TPU transport)
-#
-# comm_buf holds TWO message slots so consecutive hops never alias: hop
-# s's payload (slot s%2) stays intact while hop s+1's copy lands in the
-# other slot.  Each hop currently start()s and wait()s its RDMA before
-# combining — correct but serial within a hop; overlap of the incoming
-# copy with the VPU add (wait only the recv semaphore, drain sends
-# lazily) requires real-ICI validation and is deliberately left to
-# TPU bring-up.  The pipelining shipped today is the bidirectional ref
-# rings (ref.py): two half-width messages in flight per hop.
-
-def _ring_rs_kernel(x_ref, out_ref, comm_buf, send_sem, recv_sem):
-    """Ring reduce-scatter over axis 0 of the device ring.
-
-    x_ref: (g, c) local chunks; out_ref: (c,) fully-reduced chunk owned
-    by this device (device r ends owning chunk r, matching tiled
-    ``psum_scatter``).
-    """
-    g = pl.num_programs(0)
-    my_id = pl.program_id(0)
-    dst = (my_id + 1) % g
-
-    # hop 0's payload: our own value of chunk (r - 1)
-    comm_buf[0] = x_ref[(my_id - 1) % g]
-    for s in range(1, g):
-        slot = s % 2
-        prev = (s - 1) % 2
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=comm_buf.at[prev],
-            dst_ref=comm_buf.at[slot],
-            send_sem=send_sem.at[prev],
-            recv_sem=recv_sem.at[slot],
-            device_id=(dst,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        rdma.start()
-        rdma.wait()
-        # received partial of chunk (r - 1 - s); add our contribution
-        comm_buf[slot] = comm_buf[slot] + x_ref[(my_id - 1 - s) % g]
-    out_ref[...] = comm_buf[(g - 1) % 2]
-
-
-def _ring_ag_kernel(shard_ref, out_ref, comm_buf, send_sem, recv_sem):
-    """Ring all-gather: device r starts with chunk r, ends with all g."""
-    g = pl.num_programs(0)
-    my_id = pl.program_id(0)
-    dst = (my_id + 1) % g
-
-    out_ref[my_id] = shard_ref[...]
-    comm_buf[0] = shard_ref[...]
-    for s in range(1, g):
-        slot = s % 2
-        prev = (s - 1) % 2
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=comm_buf.at[prev],
-            dst_ref=comm_buf.at[slot],
-            send_sem=send_sem.at[prev],
-            recv_sem=recv_sem.at[slot],
-            device_id=(dst,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        rdma.start()
-        rdma.wait()
-        out_ref[(my_id - s) % g] = comm_buf[slot]
-
-
-def ring_reduce_scatter_tpu(x2d: jax.Array) -> jax.Array:  # pragma: no cover
-    """x2d: (g, c) per-device chunk view → (c,) reduced shard.  Requires a
-    real TPU ring (one program per device along grid axis 0)."""
-    if pltpu is None:
-        raise NotImplementedError("RDMA ring requires pallas TPU support")
-    g, c = x2d.shape
-    return pl.pallas_call(
-        _ring_rs_kernel,
-        grid=(g,),
-        out_shape=jax.ShapeDtypeStruct((c,), x2d.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, c), x2d.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        compiler_params=_COMPILER_PARAMS(collective_id=0),
-    )(x2d)
-
-
-def ring_all_gather_tpu(shard: jax.Array, g: int) -> jax.Array:  # pragma: no cover
-    """shard: (c,) owned chunk → (g, c) gathered buffer (ravel to 1-D)."""
-    if pltpu is None:
-        raise NotImplementedError("RDMA ring requires pallas TPU support")
-    c = shard.shape[0]
-    return pl.pallas_call(
-        _ring_ag_kernel,
-        grid=(g,),
-        out_shape=jax.ShapeDtypeStruct((g, c), shard.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, c), shard.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        compiler_params=_COMPILER_PARAMS(collective_id=1),
-    )(shard)

@@ -1,24 +1,24 @@
 """Fused comm-staging + ring collectives: the public API.
 
-Three implementation tiers, selected per call (``impl=``) or
-automatically by backend:
+Three implementation tiers, selected per call (``impl=``):
 
-  kernel   — the Pallas kernels (``kernel.py``).  The real path on TPU;
-             interpret mode everywhere else (tests).
-  xla      — a fused XLA emission: pack concatenates in the source dtype
-             and runs ONE cast(+loss-scale) pass over the whole buffer;
-             unpack is static ``lax.slice`` + cast (fusion-friendly —
-             no dynamic offsets).  The production path on CPU/GPU, and
-             measurably faster than leafwise (benchmarks/run.py
-             ``pack`` section).
+  xla      — the default on every backend: a fused XLA emission.  Pack
+             concatenates in the source dtype and runs ONE
+             cast(+loss-scale) pass over the whole buffer; unpack is
+             static ``lax.slice`` + cast (fusion-friendly — no dynamic
+             offsets).
+  kernel   — the Pallas kernels (``kernel.py``), only when asked for.
+             They do not compile for the TPU at real bucket sizes
+             (unaligned VMEM slices, whole bucket in VMEM), so they run
+             in interpret mode off the chip and are tested there.
   leafwise — the seed's per-leaf emission (``ref.py``), kept as the
              oracle and the fallback for buckets the fused path cannot
              take (non-float dtypes).
 
 The ring collectives run the chunked, bidirectional (double-buffered)
-``ppermute`` rings from ``ref.py`` — on TPU each hop lowers to the same
-ICI DMA the RDMA kernels issue by hand — with the per-hop accumulate
-optionally routed through the Pallas ``ring_accum_kernel``.  Device
+``ppermute`` rings from ``ref.py`` — on TPU each hop lowers to an ICI
+DMA — with the per-hop accumulate optionally routed through the Pallas
+``ring_accum_kernel``.  Device
 ``r`` owns chunk ``r`` after reduce-scatter, so they are drop-in for
 ``psum_scatter``/``all_gather`` (tiled) anywhere in the repo: the
 ``ring`` reducer, rsag's two-phase ops, the hierarchical fast-tier
@@ -41,6 +41,9 @@ from repro.kernels.collectives.kernel import (
 
 _FLOATS = (jnp.float32, jnp.bfloat16, jnp.float16, jnp.float64)
 
+# the staging tier every bucket takes unless a caller names another
+DEFAULT_STAGING = "xla"
+
 
 def staging_supported(leaf_dtypes, comm_dtype) -> bool:
     """Fused staging handles float↔float casts; anything else (int grads,
@@ -49,14 +52,10 @@ def staging_supported(leaf_dtypes, comm_dtype) -> bool:
     return all(jnp.dtype(d) in [jnp.dtype(f) for f in _FLOATS] for d in dts)
 
 
-def _auto_impl() -> str:
-    return "kernel" if jax.default_backend() == "tpu" else "xla"
-
-
 # -------------------------------------------------------------- staging
 
 def fused_pack(bucket, flat_leaves: Sequence[jax.Array], comm_dtype, *,
-               scale: float = 1.0, impl: str | None = None,
+               scale: float = 1.0, impl: str = DEFAULT_STAGING,
                interpret: bool = False) -> jax.Array:
     """CopyFromTo(g, comm_buf), fused: one staging pass over the bucket.
 
@@ -64,7 +63,6 @@ def fused_pack(bucket, flat_leaves: Sequence[jax.Array], comm_dtype, *,
     gradient list it indexes into.  ``scale`` is the optional loss-scale
     folded into the cast.
     """
-    impl = impl or _auto_impl()
     leaves = [jnp.ravel(flat_leaves[l.index]) for l in bucket.leaves]
     if impl == "kernel":
         return pack_bucket_kernel(
@@ -85,11 +83,10 @@ def fused_pack(bucket, flat_leaves: Sequence[jax.Array], comm_dtype, *,
 
 
 def fused_unpack(bucket, buf: jax.Array, flat_out: list, *,
-                 scale: float = 1.0, impl: str | None = None,
+                 scale: float = 1.0, impl: str = DEFAULT_STAGING,
                  interpret: bool = False) -> None:
     """CopyFromTo(recv_buf, g), fused: scatter the reduced buffer back
     into ``flat_out`` (cast-back + inverse loss-scale in the same pass)."""
-    impl = impl or _auto_impl()
     sizes = [l.size for l in bucket.leaves]
     dtypes = [l.dtype for l in bucket.leaves]
     if impl == "kernel":

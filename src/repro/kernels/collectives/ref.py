@@ -9,9 +9,8 @@ dtypes).
 
 ``ring_reduce_scatter_ref``/``ring_all_gather_ref`` are the chunked,
 ``ppermute``-based rings: g-1 neighbor hops over one mesh axis, each hop
-one ``lax.ppermute`` (XLA lowers it to the ICI DMA the RDMA kernels
-issue by hand) plus an accumulate.  ``bidirectional=True`` splits every
-chunk in half and runs a clockwise and a counter-clockwise ring at once
+one ``lax.ppermute`` (on TPU, an ICI DMA) plus an accumulate.
+``bidirectional=True`` splits every chunk in half and runs a clockwise and a counter-clockwise ring at once
 — two messages in flight per hop (the double-buffering), using both link
 directions.  Device ``r`` ends owning chunk ``r``, matching tiled
 ``psum_scatter``/``all_gather`` exactly.

@@ -1,13 +1,29 @@
-"""Production meshes.  Functions, not module-level constants — importing
-this module never touches jax device state."""
+"""Meshes.  Functions, not module-level constants — importing this module
+never touches jax device state."""
 from __future__ import annotations
+
+from typing import Sequence
 
 import jax
 from jax.sharding import AxisType
 
 
+def make_local_mesh(tp: int = 1, devices: Sequence | None = None):
+    """("data", "model") mesh over the local devices (``jax.devices()``
+    unless ``devices`` is given): ``model = tp``, ``data = count // tp``.
+    This is the mesh the launchers run on, one chip or a 2x2 host alike."""
+    devs = list(jax.devices() if devices is None else devices)
+    if tp < 1 or len(devs) % tp:
+        raise ValueError(
+            f"--tp {tp} must divide the {len(devs)} local devices")
+    return jax.make_mesh((len(devs) // tp, tp), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devs)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
-    """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
+    """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips):
+    the dry-run and simulator target, not something a launcher can
+    build on one host."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return jax.make_mesh(shape, axes,

@@ -1,13 +1,17 @@
 """Production serving launcher: continuous-batching (default) or static
 batched decoding for any arch with a serve path.
 
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b --smoke
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b --smoke \
         --temperature 0.8 --top-k 16 --seed 7
 
-Defaults keep greedy decoding (temperature 0) and the continuous engine
-for families with a paged decode hook; ``--engine static`` forces the
-original ``RequestQueue`` batcher.
+Without ``--smoke`` the arch's full config serves from a mesh over the
+local devices (``data = count // tp``, ``model = --tp``); ``--smoke``
+serves the reduced config from one device.  Defaults keep greedy
+decoding (temperature 0) and the continuous engine for families with a
+paged decode hook; ``--engine static`` forces the original
+``RequestQueue`` batcher.
 """
 from __future__ import annotations
 
@@ -18,7 +22,8 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_local_mesh, make_smoke_mesh
 from repro.models.registry import family_of
 from repro.parallel.sharding import dp_axes_of
 from repro.runtime import ContinuousScheduler, SamplingParams, Server
@@ -29,7 +34,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--tp", type=int, default=1,
+                    help="'model' axis extent of the local mesh")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--batch", type=int, default=4,
@@ -51,14 +57,17 @@ def main():
                     help="per-request sampling seed base")
     args = ap.parse_args()
 
+    enable_compile_cache()
     arch = get_arch(args.arch)
     if args.smoke:
         mesh = make_smoke_mesh(1, 1)
         cfg = arch.make_smoke()
     else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+        mesh = make_local_mesh(args.tp)
         cfg = arch.make_config(tp=mesh.shape["model"],
                                dp_axes=dp_axes_of(mesh))
+    print(f"[serve] {jax.devices()[0].platform} "
+          f"{jax.devices()[0].device_kind} mesh {dict(mesh.shape)}")
     api = family_of(cfg)
     if api.prefill is None:
         raise SystemExit(f"{args.arch} has no serve path")

@@ -3,10 +3,12 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b \
         --steps 100 --strategy depcha [--smoke]
 
-``--smoke`` runs the arch's reduced config on the local device mesh (the
-CPU-runnable path); without it the full config targets the production
-mesh (requires a real 256-chip slice — on this container use
-``repro.launch.dryrun`` instead, which AOT-compiles the same program).
+Without ``--smoke`` the arch's full config runs on a mesh over the local
+devices (``data = count // tp``, ``model = --tp``) at the arch's first
+shape; ``--batch``/``--seq`` override that shape where the host cannot
+hold its global batch (the LM shapes assume 256 chips).  ``--smoke``
+runs the reduced config on one device.  ``repro.launch.dryrun``
+AOT-compiles the full config for the 256/512-chip production mesh.
 """
 from __future__ import annotations
 
@@ -25,7 +27,8 @@ from repro.core import (
 )
 import repro.sim  # noqa: F401  (registers "auto" → --strategy auto)
 from repro.data import ImagePipeline, TokenPipeline
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_local_mesh, make_smoke_mesh
 from repro.models.registry import family_of
 from repro.optim import adamw, cosine_warmup, sgd, zero1
 from repro.parallel.sharding import dp_axes_of
@@ -66,11 +69,16 @@ def main():
                     help="keep the final microbatch inside the "
                          "accumulation scan (sync waits for the whole "
                          "scan) instead of peeling it for overlap")
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--tp", type=int, default=1,
+                    help="'model' axis extent of the local mesh")
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced config on local devices")
-    ap.add_argument("--seq", type=int, default=64)
-    ap.add_argument("--batch", type=int, default=8)
+                    help="reduced config on one local device")
+    ap.add_argument("--seq", type=int, default=None,
+                    help="sequence length (default: the arch's first "
+                         "shape; 64 with --smoke)")
+    ap.add_argument("--batch", type=int, default=None,
+                    help="global batch (default: the arch's first "
+                         "shape; 8 with --smoke)")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--lr", type=float, default=3e-4)
@@ -81,23 +89,28 @@ def main():
                     help="write the final metrics snapshot here")
     args = ap.parse_args()
 
+    enable_compile_cache()
     arch = get_arch(args.arch)
     if args.smoke:
         mesh = make_smoke_mesh(1, 1, stage=args.pp_stages
                                if args.pp_stages > 1 else 0)
         cfg = arch.make_smoke()
-        seq, batch = args.seq, args.batch
+        seq, batch = args.seq or 64, args.batch or 8
     else:
         if args.pp_stages > 1:
             raise SystemExit(
                 "--pp-stages needs the smoke mesh (--smoke); the "
-                "production mesh has no 'stage' axis")
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+                "local mesh has no 'stage' axis")
+        mesh = make_local_mesh(args.tp)
         cfg = arch.make_config(
             tp=mesh.shape["model"], dp_axes=dp_axes_of(mesh),
             depcha_in_scan=get_strategy(args.strategy).uses_in_scan)
         shape = arch.shapes[0]
-        seq, batch = shape.seq_len, shape.global_batch
+        seq = args.seq or shape.seq_len
+        batch = args.batch or shape.global_batch
+    print(f"[train] {jax.devices()[0].platform} "
+          f"{jax.devices()[0].device_kind} mesh {dict(mesh.shape)} "
+          f"batch {batch}" + (f" seq {seq}" if seq else ""))
 
     api = family_of(cfg)
     if arch.family in ("resnet", "inception"):
@@ -141,9 +154,11 @@ def main():
         if args.ckpt_dir else None
     trainer = Trainer(ts, pipe, ckpt, log_every=10,
                       events_path=args.events_jsonl or None)
-    # init_opt derives zero1 shard sizes from the step's LOCAL shapes
-    # (opt.init on global TP-sharded params would size them wrong)
-    opt_state = ts.init_opt() if args.zero1 else opt.init(params)
+    # place the state on the step's shardings (the mesh may span several
+    # chips); init_opt derives zero1 shard sizes from the step's LOCAL
+    # shapes (opt.init on global TP-sharded params would size them wrong)
+    params = jax.device_put(params, ts.shardings(ts.param_specs))
+    opt_state = ts.init_opt()
     # (deferred plan: checkpoints keep params + opt_state["pending"]
     # consistent, so resume is exact as-is; a consumer exporting params
     # must flush the carried shards with ts.finalize(params, opt_state))

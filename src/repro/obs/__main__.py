@@ -5,8 +5,6 @@ import os
 
 os.environ.setdefault(
     "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
-import repro  # noqa: F401,E402  (jax compat shim before jax imports)
 from repro.obs.cli import main  # noqa: E402
 
 main()

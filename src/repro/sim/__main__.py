@@ -13,8 +13,6 @@ auto-tuned winner, all on CPU in seconds (no compile, no hardware).
 """
 
 import argparse
-
-import repro  # noqa: F401  (jaxcompat shim before jax.sharding imports)
 import jax  # noqa: F401
 
 from repro.configs import get_arch

@@ -23,8 +23,6 @@ warnings.filterwarnings("ignore")
 import dataclasses
 import shutil
 import tempfile
-
-import repro  # noqa: F401  (applies the jaxcompat shim before jax imports)
 import jax
 import jax.numpy as jnp
 import numpy as np

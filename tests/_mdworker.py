@@ -9,8 +9,6 @@ import warnings
 
 warnings.filterwarnings("ignore")
 import dataclasses
-
-import repro  # noqa: F401  (applies the jaxcompat shim before jax imports)
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -142,7 +140,7 @@ check("strategies-identical-grads-8dev", ok)
 #    SAME mesh, matches plain adamw at dp=1, rides the ring transport,
 #    and clips via the scheduled NORM op exactly like
 #    clip_by_global_norm does on the flat path.
-from repro.optim import adamw, zero1
+from repro.optim import adamw, sgd, zero1
 from repro.runtime import make_train_step
 from repro.data import TokenPipeline
 
@@ -403,7 +401,11 @@ def run_steps(mode, n, *, clip_norm=0.0, microbatch=1,
     params = family_of(cfg).init(jax.random.PRNGKey(2), mk_dense(1))
     b0 = pipe8.batch_at(0)
     if mode == "flat":
-        opt = adamw(1e-3)
+        # SGD: a microbatch count that scaled the gradient would scale
+        # the update (Adam normalizes it away), and round-off from the
+        # accumulation order is not amplified by lr/eps at near-zero
+        # gradients as it is in Adam's first step
+        opt = sgd(0.1)
         sync = GradSyncConfig(strategy="concom", bucket_bytes=1 << 12)
         ts = make_train_step(cfg, mesh8, sync, opt, batch_like=b0,
                              params_like=params, clip_norm=clip_norm,
@@ -619,20 +621,21 @@ def pp_steps(mesh, stage, schedule, microbatch, n=2, clip=0.0):
                          pp_stages=stage, pp_schedule=schedule)
     ps = jax.device_put(params, ts.shardings(ts.param_specs))
     st = ts.init_opt()
-    m = None
+    ms = []
     for k in range(n):
         ps, st, m = ts.fn(ps, st, pipe.batch_at(k), jnp.int32(k))
-    return ps, m
+        ms.append(m)
+    return ps, ms
 
 
-pg2, mg2 = pp_steps(mesh_pp2, 2, "gpipe", 4)
-pg1, mg1 = pp_steps(mesh_pp1, 1, "gpipe", 4)
+pg2, (*_, mg2) = pp_steps(mesh_pp2, 2, "gpipe", 4)
+pg1, (*_, mg1) = pp_steps(mesh_pp1, 1, "gpipe", 4)
 check("pp-gpipe-bitexact-vs-stage1",
       worst_diff(pg2, pg1) == 0.0
       and float(mg2["loss"]) == float(mg1["loss"]))
 
 # 1f1b at M == S: one chunk of S microbatches == the GPipe wave program
-pf2, mf2 = pp_steps(mesh_pp2, 2, "1f1b", 2)
+pf2, _ = pp_steps(mesh_pp2, 2, "1f1b", 2)
 pw1, _ = pp_steps(mesh_pp1, 1, "gpipe", 2)
 check("pp-1f1b-m-eq-s-bitexact-vs-stage1", worst_diff(pf2, pw1) == 0.0)
 
@@ -641,10 +644,12 @@ pf4, _ = pp_steps(mesh_pp2, 2, "1f1b", 4)
 pf4r, _ = pp_steps(mesh_pp1, 1, "1f1b", 4)
 check("pp-1f1b-m4-close-vs-stage1", worst_diff(pf4, pf4r) < 1e-5)
 
-# clipped: gnorm is bit-identical across stagings (per-leaf psum in the
-# same layer order); the clip×adamw fusion is float round-off
-pc2, mc2 = pp_steps(mesh_pp2, 2, "gpipe", 4, clip=0.05)
-pc1, mc1 = pp_steps(mesh_pp1, 1, "gpipe", 4, clip=0.05)
+# clipped: the first step's gnorm (same params on both stagings) is
+# bit-identical (per-leaf psum in the same layer order); the clip×adamw
+# fusion is float round-off, so later steps' gnorms are held only to the
+# params' closeness
+pc2, (mc2, _) = pp_steps(mesh_pp2, 2, "gpipe", 4, clip=0.05)
+pc1, (mc1, _) = pp_steps(mesh_pp1, 1, "gpipe", 4, clip=0.05)
 check("pp-clip-gnorm-bitexact",
       float(mc2["grad_norm"]) == float(mc1["grad_norm"]))
 check("pp-clip-close-vs-stage1", worst_diff(pc2, pc1) < 1e-5)

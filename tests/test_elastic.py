@@ -4,8 +4,6 @@ plus the full multi-device elastic cycle in a subprocess worker
 import os
 import subprocess
 import sys
-
-import repro  # noqa: F401  (applies the jaxcompat shim before jax imports)
 import jax
 import jax.numpy as jnp
 import numpy as np

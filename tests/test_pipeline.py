@@ -8,7 +8,6 @@ WORKER = r'''
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import warnings; warnings.filterwarnings("ignore")
-import repro  # applies the jaxcompat shim before jax imports
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.parallel.pipeline import pipeline_forward, bubble_fraction

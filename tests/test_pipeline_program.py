@@ -156,7 +156,6 @@ WORKER = r'''
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import warnings; warnings.filterwarnings("ignore")
-import repro  # applies the jaxcompat shim before jax imports
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import AxisType, PartitionSpec as P
 from repro.core.buckets import Bucket, BucketPlan, LeafInfo

@@ -216,7 +216,7 @@ def pipe_setup(smoke_mesh):
 
 
 def _make_step(cfg, pipe, params, mesh, *, mode=None, microbatch=1,
-               accum_overlap=True, clip_norm=0.0):
+               accum_overlap=True, clip_norm=0.0, inner=None):
     from repro.core import GradSyncConfig
     from repro.optim import adamw, zero1
     from repro.runtime import make_train_step
@@ -229,7 +229,7 @@ def _make_step(cfg, pipe, params, mesh, *, mode=None, microbatch=1,
             adamw(1e-3), batch_like=batch, params_like=params,
             microbatch=microbatch, accum_overlap=accum_overlap,
             clip_norm=clip_norm)
-    opt = zero1(adamw(1e-3), ("data",), 1)
+    opt = zero1(inner or adamw(1e-3), ("data",), 1)
     return make_train_step(
         cfg, mesh,
         GradSyncConfig(strategy="concom", bucket_bytes=1 << 14,
@@ -293,12 +293,17 @@ def test_deferred_matches_scheduled_across_steps(pipe_setup, smoke_mesh):
 
 
 def test_deferred_clip_matches_scheduled_clip(pipe_setup, smoke_mesh):
+    from repro.optim import sgd
+
     cfg, pipe, params = pipe_setup
     clip = 0.05                              # small enough to bind
+    # SGD, not Adam: the clipped update is then proportional to the clip
+    # scale (Adam normalizes the scale away), and a 1-ulp param
+    # difference is not amplified by lr/eps at near-zero gradients
     ts_s = _make_step(cfg, pipe, params, smoke_mesh, mode="scheduled",
-                      clip_norm=clip)
+                      clip_norm=clip, inner=sgd(0.1))
     ts_d = _make_step(cfg, pipe, params, smoke_mesh, mode="deferred",
-                      clip_norm=clip)
+                      clip_norm=clip, inner=sgd(0.1))
     p_s, _, m_s = _run(ts_s, pipe, params, 2)
     p_d, s_d, m_d = _run(ts_d, pipe, params, 2)
     assert float(m_s["grad_norm"]) > clip    # the clip actually engaged
