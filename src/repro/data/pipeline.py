@@ -19,7 +19,24 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.obs import span
 from repro.parallel.sharding import batch_spec
+
+
+def place(batch: dict[str, Any], mesh: Mesh | None) -> dict[str, Any]:
+    """Put a host batch on the devices, recorded as the span
+    ``data.place`` with its ``bytes``: split along the batch axes of
+    ``mesh`` (scalars replicated), or onto the default device without
+    one."""
+    with span("data.place", bytes=sum(v.nbytes for v in batch.values())):
+        if mesh is None:
+            return {k: jnp.asarray(v) for k, v in batch.items()}
+        bspec = batch_spec(mesh)
+        return {
+            k: jax.device_put(
+                v, NamedSharding(mesh, P() if np.ndim(v) == 0 else bspec))
+            for k, v in batch.items()
+        }
 
 
 class TokenPipeline:
@@ -36,29 +53,21 @@ class TokenPipeline:
         self.extra = extra_specs or {}
 
     def batch_at(self, step: int) -> dict[str, Any]:
-        rng = np.random.default_rng((self.seed, step))
-        toks = rng.integers(
-            0, self.vocab, (self.global_batch, self.seq_len + 1),
-            dtype=np.int32)
-        batch = {
-            "tokens": toks[:, :-1],
-            "labels": toks[:, 1:],
-            "global_tokens": np.float32(self.global_batch * self.seq_len),
-        }
-        for name, (shape, dtype) in self.extra.items():
-            batch[name] = rng.standard_normal(
-                (self.global_batch, *shape)).astype(dtype)
-        return self._place(batch)
-
-    def _place(self, batch):
-        if self.mesh is None:
-            return {k: jnp.asarray(v) for k, v in batch.items()}
-        bspec = batch_spec(self.mesh)
-        out = {}
-        for k, v in batch.items():
-            spec = P() if np.ndim(v) == 0 else bspec
-            out[k] = jax.device_put(v, NamedSharding(self.mesh, spec))
-        return out
+        with span("data.synth"):
+            rng = np.random.default_rng((self.seed, step))
+            toks = rng.integers(
+                0, self.vocab, (self.global_batch, self.seq_len + 1),
+                dtype=np.int32)
+            batch = {
+                "tokens": toks[:, :-1],
+                "labels": toks[:, 1:],
+                "global_tokens": np.float32(
+                    self.global_batch * self.seq_len),
+            }
+            for name, (shape, dtype) in self.extra.items():
+                batch[name] = rng.standard_normal(
+                    (self.global_batch, *shape)).astype(dtype)
+        return place(batch, self.mesh)
 
     def __iter__(self) -> Iterator[dict[str, Any]]:
         step = 0
@@ -79,23 +88,18 @@ class ImagePipeline:
         self.mesh = mesh
 
     def batch_at(self, step: int) -> dict[str, Any]:
-        rng = np.random.default_rng((self.seed, step))
-        batch = {
-            "images": rng.standard_normal(
-                (self.global_batch, self.img_size, self.img_size, 3)
-            ).astype(np.float32),
-            "labels": rng.integers(
-                0, self.num_classes, (self.global_batch,), dtype=np.int32),
-            "global_tokens": np.float32(self.global_batch),
-        }
-        if self.mesh is None:
-            return {k: jnp.asarray(v) for k, v in batch.items()}
-        bspec = batch_spec(self.mesh)
-        return {
-            k: jax.device_put(
-                v, NamedSharding(self.mesh, P() if np.ndim(v) == 0 else bspec))
-            for k, v in batch.items()
-        }
+        with span("data.synth"):
+            rng = np.random.default_rng((self.seed, step))
+            batch = {
+                "images": rng.standard_normal(
+                    (self.global_batch, self.img_size, self.img_size, 3)
+                ).astype(np.float32),
+                "labels": rng.integers(
+                    0, self.num_classes, (self.global_batch,),
+                    dtype=np.int32),
+                "global_tokens": np.float32(self.global_batch),
+            }
+        return place(batch, self.mesh)
 
     def __iter__(self):
         step = 0

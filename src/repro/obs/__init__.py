@@ -6,9 +6,10 @@ The subsystem that looks *back* at what actually ran:
   events      — JSONL event stream + heartbeat line
   provenance  — the metadata header every BENCH_*/profile artifact embeds
   measure     — per-op measured replay emitting sim-compatible Timelines
+  spans       — host spans on the profiler's clock + their in-process ring
   calibrate   — alpha-beta NetworkModel fits + per-mesh fitted profiles
 
-``measure`` (and anything importing jax) is imported lazily so the
+``measure``, ``spans`` (and anything importing jax) is imported lazily so the
 pure-host pieces stay usable from no-jax contexts (the analysis CLI).
 """
 from repro.obs.events import EventLog, heartbeat_line, utc_now
@@ -28,12 +29,17 @@ __all__ = [
     "Histogram",
     "MetricsRegistry",
     "SCHEMA_VERSION",
+    "Span",
+    "SpanRecorder",
     "bench_metadata",
     "comm_byte_counters",
     "heartbeat_line",
     "host_time_us",
     "measured_gradsync",
     "measured_timeline",
+    "recorded_spans",
+    "span",
+    "step_span",
     "utc_now",
 ]
 
@@ -46,6 +52,11 @@ _LAZY = {
     "fitted_network": "repro.obs.calibrate",
     "load_profile": "repro.obs.calibrate",
     "save_profile": "repro.obs.calibrate",
+    "Span": "repro.obs.spans",
+    "SpanRecorder": "repro.obs.spans",
+    "recorded_spans": "repro.obs.spans",
+    "span": "repro.obs.spans",
+    "step_span": "repro.obs.spans",
 }
 
 

@@ -701,11 +701,85 @@ class Trainer:
         except Exception:
             pass    # an estimate must never take down training
 
+    def _account(self, step: int, dt: float, tokens: int, metrics,
+                 losses, params, opt_state, first: bool,
+                 consec_slow: int) -> int:
+        """After a committed step: step-time stats, straggler check,
+        loss readback, metrics, events, heartbeat and the checkpoint of
+        the post-step state; returns the new run of slow steps."""
+        from repro.obs import heartbeat_line
+
+        if first:
+            # the first executed step spans the jit warmup compile:
+            # report it separately, keep it out of every throughput
+            # stat (step_times, histograms, tokens/s, stragglers)
+            self.compile_time = dt
+            self.metrics.gauge("compile_time_s").set(dt)
+            self._event("compile", step=step, dt=dt)
+        else:
+            if len(self.step_times) >= 5:
+                med = statistics.median(self.step_times[-50:])
+                if dt > self.straggler_factor * med:
+                    consec_slow += 1
+                    self._event("straggler", step=step, dt=dt,
+                                median=med)
+                    if consec_slow >= self.straggler_patience:
+                        decision = (self.remesh_hook(step)
+                                    if self.remesh_hook else None)
+                        self._event("remesh_requested", step=step,
+                                    decision=decision or "log-only")
+                        self.printer(
+                            f"[trainer] {consec_slow} consecutive "
+                            f"straggler steps — requesting re-shard / "
+                            f"hot-spare swap "
+                            f"({decision or 'log-only'})")
+                        consec_slow = 0
+                        if decision == "shrink":
+                            # hand the committed post-step state to
+                            # the supervisor; resume at step + 1
+                            e = RemeshRequest(
+                                f"straggler shrink @ {step}")
+                            e.step = step + 1
+                            e.params, e.opt_state = params, opt_state
+                            raise e
+                else:
+                    consec_slow = 0
+            self.step_times.append(dt)
+            self.metrics.histogram("step_time_s").observe(dt)
+            if tokens:
+                self.metrics.counter("tokens_total").inc(tokens)
+                self.metrics.gauge("tokens_per_s").set(tokens / dt)
+
+        loss = float(metrics["loss"])
+        gnorm = float(metrics.get("grad_norm", 0.0))
+        losses.append(loss)
+        self.metrics.counter("steps_total").inc()
+        self.metrics.gauge("loss").set(loss)
+        self.metrics.gauge("grad_norm").set(gnorm)
+        self.event_log.emit(
+            "step", step=step, loss=loss, dt=dt, grad_norm=gnorm,
+            tokens=tokens, compile_step=self.compile_time == dt)
+        if step % self.log_every == 0:
+            self.printer(
+                f"[trainer] step {step} loss {losses[-1]:.4f} "
+                f"({dt*1e3:.1f} ms)")
+            avg = (sum(self.step_times[-50:])
+                   / max(len(self.step_times[-50:]), 1) * 1e3
+                   if self.step_times else None)
+            self.printer(heartbeat_line(
+                step, loss=loss, step_ms=dt * 1e3, avg_ms=avg,
+                tokens_per_s=(tokens / dt if tokens else None),
+                grad_norm=gnorm, compile_s=self.compile_time))
+        if self.ckpt is not None:
+            self.ckpt.maybe_save(
+                step + 1, {"params": params, "opt": opt_state})
+        return consec_slow
+
     def run(self, params, opt_state, num_steps: int,
             start_step: int = 0) -> tuple[Any, Any, dict]:
         from collections import deque
 
-        from repro.obs import heartbeat_line
+        from repro.obs import span, step_span
 
         step = start_step
         if self.ckpt is not None and self.ckpt.latest() is not None:
@@ -725,130 +799,74 @@ class Trainer:
         retries_used = 0
         first_timed = self.compile_time is None
         while step < num_steps:
-            batch = self.pipeline.batch_at(step)
-            tokens = sum(
-                int(np.prod(v.shape)) for k, v in batch.items()
-                if k == "tokens") if isinstance(batch, dict) else 0
-            t0 = time.perf_counter()
-            try:
-                # injected faults fire at the top of the attempt — AFTER
-                # t0, so a straggler sleep injected here counts in dt
-                if self.fault_injector is not None:
-                    self.fault_injector(step)
-                if step in self.fail_at:
-                    self.fail_at.discard(step)
-                    raise SimulatedFailure(f"injected node loss @ {step}")
-                params, opt_state, metrics = self.step_fn.fn(
-                    params, opt_state, batch, jnp.int32(step))
-                jax.block_until_ready(metrics["loss"])
-                retries_used = 0
-            except TransientStepError as e:
-                # rung 1: the step never committed state — retry in place
-                retries_used += 1
-                if retries_used <= self.step_retries:
-                    self._event("retry", step=step, attempt=retries_used)
-                    self.printer(f"[trainer] transient fault @ {step} "
-                                 f"({e}); retry {retries_used}/"
-                                 f"{self.step_retries}")
+            with step_span("train.step", step):
+                with span("train.input"):
+                    batch = self.pipeline.batch_at(step)
+                tokens = sum(
+                    int(np.prod(v.shape)) for k, v in batch.items()
+                    if k == "tokens") if isinstance(batch, dict) else 0
+                t0 = time.perf_counter()
+                try:
+                    # injected faults fire at the top of the attempt —
+                    # AFTER t0, so a straggler sleep injected here counts
+                    # in dt
+                    if self.fault_injector is not None:
+                        self.fault_injector(step)
+                    if step in self.fail_at:
+                        self.fail_at.discard(step)
+                        raise SimulatedFailure(f"injected node loss @ {step}")
+                    with span("train.dispatch"):
+                        params, opt_state, metrics = self.step_fn.fn(
+                            params, opt_state, batch, jnp.int32(step))
+                    with span("train.wait"):
+                        jax.block_until_ready(metrics["loss"])
+                    retries_used = 0
+                except TransientStepError as e:
+                    # rung 1: the step never committed state — retry in place
+                    retries_used += 1
+                    if retries_used <= self.step_retries:
+                        self._event("retry", step=step, attempt=retries_used)
+                        self.printer(f"[trainer] transient fault @ {step} "
+                                     f"({e}); retry {retries_used}/"
+                                     f"{self.step_retries}")
+                        continue
+                    retries_used = 0
+                    self._event("retry_exhausted", step=step)
+                    self.printer(f"[trainer] {e}; retries exhausted — "
+                                 f"recovering from checkpoint")
+                    recovered = self._recover(params, opt_state)
+                    if recovered is None:
+                        self.printer("[trainer] no checkpoint; restart from 0")
+                        step = start_step
+                        continue
+                    step, params, opt_state = recovered
                     continue
-                retries_used = 0
-                self._event("retry_exhausted", step=step)
-                self.printer(f"[trainer] {e}; retries exhausted — "
-                             f"recovering from checkpoint")
-                recovered = self._recover(params, opt_state)
-                if recovered is None:
-                    self.printer("[trainer] no checkpoint; restart from 0")
-                    step = start_step
+                except RankLost as e:
+                    # rung 3 lives OUTSIDE the loop: a lost rank means this
+                    # mesh is gone — hand the last committed state to the
+                    # supervisor (repro.elastic) and unwind
+                    e.step = step
+                    e.params, e.opt_state = params, opt_state
+                    self._event("rank_lost", step=step)
+                    self.printer(f"[trainer] {e}; surrendering to supervisor")
+                    raise
+                except SimulatedFailure as e:
+                    self._event("failure", step=step)
+                    self.printer(f"[trainer] {e}; recovering from checkpoint")
+                    recovered = self._recover(params, opt_state)
+                    if recovered is None:
+                        self.printer("[trainer] no checkpoint; restart from 0")
+                        step = start_step
+                        continue
+                    step, params, opt_state = recovered
                     continue
-                step, params, opt_state = recovered
-                continue
-            except RankLost as e:
-                # rung 3 lives OUTSIDE the loop: a lost rank means this
-                # mesh is gone — hand the last committed state to the
-                # supervisor (repro.elastic) and unwind
-                e.step = step
-                e.params, e.opt_state = params, opt_state
-                self._event("rank_lost", step=step)
-                self.printer(f"[trainer] {e}; surrendering to supervisor")
-                raise
-            except SimulatedFailure as e:
-                self._event("failure", step=step)
-                self.printer(f"[trainer] {e}; recovering from checkpoint")
-                recovered = self._recover(params, opt_state)
-                if recovered is None:
-                    self.printer("[trainer] no checkpoint; restart from 0")
-                    step = start_step
-                    continue
-                step, params, opt_state = recovered
-                continue
 
-            dt = time.perf_counter() - t0
-            if first_timed:
-                # the first executed step spans the jit warmup compile:
-                # report it separately, keep it out of every throughput
-                # stat (step_times, histograms, tokens/s, stragglers)
+                with span("train.account"):
+                    consec_slow = self._account(
+                        step, time.perf_counter() - t0, tokens, metrics,
+                        losses, params, opt_state, first_timed, consec_slow)
                 first_timed = False
-                self.compile_time = dt
-                self.metrics.gauge("compile_time_s").set(dt)
-                self._event("compile", step=step, dt=dt)
-            else:
-                if len(self.step_times) >= 5:
-                    med = statistics.median(self.step_times[-50:])
-                    if dt > self.straggler_factor * med:
-                        consec_slow += 1
-                        self._event("straggler", step=step, dt=dt,
-                                    median=med)
-                        if consec_slow >= self.straggler_patience:
-                            decision = (self.remesh_hook(step)
-                                        if self.remesh_hook else None)
-                            self._event("remesh_requested", step=step,
-                                        decision=decision or "log-only")
-                            self.printer(
-                                f"[trainer] {consec_slow} consecutive "
-                                f"straggler steps — requesting re-shard / "
-                                f"hot-spare swap "
-                                f"({decision or 'log-only'})")
-                            consec_slow = 0
-                            if decision == "shrink":
-                                # hand the committed post-step state to
-                                # the supervisor; resume at step + 1
-                                e = RemeshRequest(
-                                    f"straggler shrink @ {step}")
-                                e.step = step + 1
-                                e.params, e.opt_state = params, opt_state
-                                raise e
-                    else:
-                        consec_slow = 0
-                self.step_times.append(dt)
-                self.metrics.histogram("step_time_s").observe(dt)
-                if tokens:
-                    self.metrics.counter("tokens_total").inc(tokens)
-                    self.metrics.gauge("tokens_per_s").set(tokens / dt)
-
-            loss = float(metrics["loss"])
-            gnorm = float(metrics.get("grad_norm", 0.0))
-            losses.append(loss)
-            self.metrics.counter("steps_total").inc()
-            self.metrics.gauge("loss").set(loss)
-            self.metrics.gauge("grad_norm").set(gnorm)
-            self.event_log.emit(
-                "step", step=step, loss=loss, dt=dt, grad_norm=gnorm,
-                tokens=tokens, compile_step=self.compile_time == dt)
-            if step % self.log_every == 0:
-                self.printer(
-                    f"[trainer] step {step} loss {losses[-1]:.4f} "
-                    f"({dt*1e3:.1f} ms)")
-                avg = (sum(self.step_times[-50:])
-                       / max(len(self.step_times[-50:]), 1) * 1e3
-                       if self.step_times else None)
-                self.printer(heartbeat_line(
-                    step, loss=loss, step_ms=dt * 1e3, avg_ms=avg,
-                    tokens_per_s=(tokens / dt if tokens else None),
-                    grad_norm=gnorm, compile_s=self.compile_time))
-            step += 1
-            if self.ckpt is not None:
-                self.ckpt.maybe_save(
-                    step, {"params": params, "opt": opt_state})
+                step += 1
 
         if self.ckpt is not None:
             self.ckpt.wait()

@@ -1,12 +1,15 @@
 """repro.obs: metrics semantics, event stream, provenance headers,
-NetworkModel/StagingModel calibration round-trips, fitted-profile
-consumption by `auto`, the Trainer's compile-time separation, and the
-merged sim+measured trace (subprocess — needs 8 fake devices)."""
+the span recorder, NetworkModel/StagingModel calibration round-trips,
+fitted-profile consumption by `auto`, the Trainer's compile-time
+separation and step spans, and the merged sim+measured trace
+(subprocess — needs 8 fake devices)."""
+import collections
 import io
 import json
 import os
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -147,6 +150,140 @@ def test_comm_byte_counters_account_wire_kinds_only():
     assert any(k.startswith("comm_bytes.all_gather.") for k in snap2)
     # UPDATE/NORM ops move no payload → never counted
     assert not any("update" in k or "norm" in k for k in snap2)
+
+
+# ---------------------------------------------------------------- spans
+
+def test_spans_nest_with_parents_steps_and_attrs():
+    from repro.obs import SpanRecorder
+
+    rec = SpanRecorder()
+    with rec.step_span("train.step", 7):
+        with rec.span("train.input", bytes=12):
+            with rec.span("data.synth"):
+                pass
+        with rec.span("train.wait"):
+            pass
+    with rec.span("free"):
+        pass
+    got = rec.spans()
+    # recorded as each block exits
+    assert [s.name for s in got] == ["data.synth", "train.input",
+                                     "train.wait", "train.step", "free"]
+    by = {s.name: s for s in got}
+    assert by["data.synth"].parent == "train.input"
+    assert by["train.input"].parent == "train.step"
+    assert by["train.wait"].parent == "train.step"
+    assert by["train.step"].parent is None and by["free"].parent is None
+    assert [s.step for s in got] == [7, 7, 7, 7, None]
+    assert by["train.input"].attrs == {"bytes": 12}
+    outer = by["train.step"]
+    for s in got[:3]:
+        assert outer.start_ns <= s.start_ns <= s.end_ns <= outer.end_ns
+
+
+def test_span_is_recorded_when_its_block_raises():
+    from repro.obs import SpanRecorder
+
+    rec = SpanRecorder()
+    with pytest.raises(KeyError):
+        with rec.step_span("train.step", 3):
+            with rec.span("train.input"):
+                raise KeyError("window closed")
+    assert [(s.name, s.step) for s in rec.spans()] == [
+        ("train.input", 3), ("train.step", 3)]
+    with rec.span("after"):
+        pass
+    assert rec.spans()[-1].parent is None      # the stack unwound
+
+
+def test_span_ring_is_bounded_and_read_by_window(monkeypatch):
+    import repro.obs.spans as spans
+
+    ticks = iter(range(0, 100, 10))      # span i runs [20 i, 20 i + 10)
+    monkeypatch.setattr(spans.time, "time_ns", lambda: next(ticks))
+    rec = spans.SpanRecorder()
+    assert rec._ring.maxlen == spans.RING_SIZE
+    rec._ring = collections.deque(maxlen=3)
+    for i in range(5):
+        with rec.span(f"s{i}"):
+            pass
+    assert [s.name for s in rec.spans()] == ["s2", "s3", "s4"]
+    # overlap with [lo, hi): a span ending at lo or starting at hi is out
+    assert [s.name for s in rec.spans(50, 80)] == ["s3"]
+    assert [s.name for s in rec.spans(45, 81)] == ["s2", "s3", "s4"]
+    assert rec.spans(90) == [] and rec.spans(None, 40) == []
+
+
+def test_span_timestamps_come_from_time_ns(monkeypatch):
+    import repro.obs.spans as spans
+
+    ticks = iter(range(100, 200, 10))
+    monkeypatch.setattr(spans.time, "time_ns", lambda: next(ticks))
+    rec = spans.SpanRecorder()
+    with rec.span("a"):
+        with rec.span("b"):
+            pass
+    assert [(s.name, s.start_ns, s.end_ns) for s in rec.spans()] == [
+        ("b", 110, 120), ("a", 100, 130)]
+    monkeypatch.undo()
+    lo = time.time_ns()
+    with rec.span("c"):
+        pass
+    c = rec.spans()[-1]
+    assert lo <= c.start_ns <= c.end_ns <= time.time_ns()
+
+
+def test_backend_compiles_are_recorded_as_spans():
+    import jax
+    import numpy as np
+
+    from repro.obs import recorded_spans, step_span
+
+    lo = time.time_ns()
+    with step_span("train.step", 4):
+        jax.jit(lambda x: x - 3)(np.ones(7, np.float32)).block_until_ready()
+    hi = time.time_ns()
+    got = [s for s in recorded_spans(lo, hi) if s.name == "jax.compile"]
+    assert len(got) == 1
+    c = got[0]
+    assert lo <= c.start_ns < c.end_ns <= hi
+    assert c.parent is None and c.step == 4 and "fun_name" in c.attrs
+
+
+def test_spans_show_in_the_profiler_trace_on_its_clock(tmp_path):
+    """Each span is also a host event of a profile taken meanwhile; the
+    profiler writes times relative to its session's start, on the clock
+    the ring reads."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.obs import recorded_spans, span, step_span
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with step_span("train.step", 11):
+            with span("train.input"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(
+        glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0])
+    origin = next(v for p in pd.planes for k, v in p.stats
+                  if k == "profile_start_time")
+    events = {e.name: e for p in pd.planes if p.name.startswith("/host:")
+              for line in p.lines for e in line.events
+              if e.name in ("train.step", "train.input")}
+    assert set(events) == {"train.step", "train.input"}
+    for s in recorded_spans(origin):
+        if s.name in events:
+            e = events[s.name]
+            # the annotation encloses the ring's reads of the clock
+            assert origin + e.start_ns <= s.start_ns
+            assert s.end_ns <= origin + e.end_ns
+            assert s.start_ns - (origin + e.start_ns) < 1e6
 
 
 # ---------------------------------------------------------- calibration
@@ -410,6 +547,28 @@ def test_trainer_bounds_loss_history(tiny_train):
     tr = Trainer(ts, pipe, None, log_every=1000, loss_window=3)
     _, _, hist = tr.run(params, opt.init(params), 6)
     assert len(hist["losses"]) == 3
+
+
+def test_trainer_records_step_spans_in_order(tiny_train):
+    from repro.obs import recorded_spans
+    from repro.runtime import Trainer
+
+    ts, pipe, params, opt = tiny_train
+    lo = time.time_ns()
+    Trainer(ts, pipe, None, log_every=1000).run(params, opt.init(params), 3)
+    got = [s for s in recorded_spans(lo, time.time_ns())
+           if s.name != "jax.compile"]
+    order = [("data.synth", "train.input"), ("data.place", "train.input"),
+             ("train.input", "train.step"), ("train.dispatch", "train.step"),
+             ("train.wait", "train.step"), ("train.account", "train.step"),
+             ("train.step", None)]
+    assert [(s.name, s.parent) for s in got] == order * 3
+    assert [s.step for s in got] == [0] * 7 + [1] * 7 + [2] * 7
+    for a, b in zip(got, got[1:]):
+        if b.parent == a.parent:            # siblings follow each other
+            assert a.end_ns <= b.start_ns
+    place = [s for s in got if s.name == "data.place"]
+    assert all(s.attrs["bytes"] == 2 * 4 * 16 * 4 + 4 for s in place)
 
 
 # --------------------------------- measured replay (8 fake devices)
