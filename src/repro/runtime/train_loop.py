@@ -572,6 +572,18 @@ class Trainer:
       running median are logged and counted; after ``straggler_patience``
       consecutive hits the (simulated) response is a re-shard event —
       on a real fleet this triggers hot-spare swap-in
+    - one step of input lookahead: once step k is dispatched, and before
+      the host waits on it, ``run`` builds step k+1's batch, so the build
+      runs while the device computes (DESIGN.md §12).  This relies on
+      ``pipeline.batch_at`` being a pure function of the step.  At most
+      one batch is built ahead and resident on the device, never for a
+      step at or past ``num_steps``, and it lives only in ``run``'s
+      locals.  An exception the lookahead build raises is kept until the
+      step it belongs to and raised there, so step k still commits.  A
+      retried step reuses its batch; a batch kept for a step that a
+      recovery or restart skips is dropped (``input_ahead_total`` counts
+      committed steps that trained on a batch built ahead,
+      ``input_ahead_dropped_total`` those dropped)
     """
 
     def __init__(self, step_fn: TrainStep, pipeline, ckpt,
@@ -775,6 +787,19 @@ class Trainer:
                 step + 1, {"params": params, "opt": opt_state})
         return consec_slow
 
+    def _build_ahead(self, step: int) -> tuple[int, Any, bool]:
+        """``step``'s batch built ahead, or the exception its build raised,
+        kept for that step: ``(step, batch or exception, True)``."""
+        from repro.obs import span
+
+        with span("train.input", ahead=True):
+            try:
+                return step, self.pipeline.batch_at(step), True
+            except Exception as e:
+                # raised at the top of ``step``, where a build in place
+                # would have raised it
+                return step, e, True
+
     def run(self, params, opt_state, num_steps: int,
             start_step: int = 0) -> tuple[Any, Any, dict]:
         from collections import deque
@@ -798,10 +823,22 @@ class Trainer:
         consec_slow = 0
         retries_used = 0
         first_timed = self.compile_time is None
+        ahead_used = self.metrics.counter("input_ahead_total")
+        ahead_dropped = self.metrics.counter("input_ahead_dropped_total")
+        # (step, its batch or the exception its build raised, built ahead)
+        kept: tuple[int, Any, bool] | None = None
         while step < num_steps:
             with step_span("train.step", step):
-                with span("train.input"):
-                    batch = self.pipeline.batch_at(step)
+                if kept is not None and kept[0] != step:
+                    if kept[2]:
+                        ahead_dropped.inc()
+                    kept = None
+                if kept is None:
+                    with span("train.input", ahead=False):
+                        kept = (step, self.pipeline.batch_at(step), False)
+                _, batch, built_ahead = kept
+                if isinstance(batch, Exception):
+                    raise batch
                 tokens = sum(
                     int(np.prod(v.shape)) for k, v in batch.items()
                     if k == "tokens") if isinstance(batch, dict) else 0
@@ -818,6 +855,8 @@ class Trainer:
                     with span("train.dispatch"):
                         params, opt_state, metrics = self.step_fn.fn(
                             params, opt_state, batch, jnp.int32(step))
+                    nxt = (self._build_ahead(step + 1)
+                           if step + 1 < num_steps else None)
                     with span("train.wait"):
                         jax.block_until_ready(metrics["loss"])
                     retries_used = 0
@@ -865,6 +904,9 @@ class Trainer:
                     consec_slow = self._account(
                         step, time.perf_counter() - t0, tokens, metrics,
                         losses, params, opt_state, first_timed, consec_slow)
+                if built_ahead:
+                    ahead_used.inc()
+                kept = nxt
                 first_timed = False
                 step += 1
 

@@ -485,30 +485,6 @@ def test_auto_prefers_fitted_profile(tmp_path, monkeypatch):
 
 # ------------------------------------------------ trainer integration
 
-@pytest.fixture(scope="module")
-def tiny_train(smoke_mesh):
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import GradSyncConfig
-    from repro.data import TokenPipeline
-    from repro.models import transformer as tf
-    from repro.optim import adamw
-    from repro.runtime import make_train_step
-
-    cfg = tf.TransformerConfig(
-        name="obs", n_layers=2, d_model=32, n_heads=4, kv_heads=2,
-        d_ff=64, vocab=64, tp=1, attn_chunk=16, dtype=jnp.float32)
-    pipe = TokenPipeline(64, 16, 4, seed=13, mesh=smoke_mesh)
-    params = tf.init_params(jax.random.PRNGKey(0), cfg)
-    opt = adamw(1e-3)
-    ts = make_train_step(
-        cfg, smoke_mesh,
-        GradSyncConfig(strategy="concom", bucket_bytes=1 << 14),
-        opt, batch_like=pipe.batch_at(0), params_like=params)
-    return ts, pipe, params, opt
-
-
 def test_trainer_separates_compile_time(tiny_train, tmp_path):
     from repro.runtime import Trainer
 
@@ -558,12 +534,18 @@ def test_trainer_records_step_spans_in_order(tiny_train):
     Trainer(ts, pipe, None, log_every=1000).run(params, opt.init(params), 3)
     got = [s for s in recorded_spans(lo, time.time_ns())
            if s.name != "jax.compile"]
-    order = [("data.synth", "train.input"), ("data.place", "train.input"),
-             ("train.input", "train.step"), ("train.dispatch", "train.step"),
-             ("train.wait", "train.step"), ("train.account", "train.step"),
-             ("train.step", None)]
-    assert [(s.name, s.parent) for s in got] == order * 3
-    assert [s.step for s in got] == [0] * 7 + [1] * 7 + [2] * 7
+    build = [("data.synth", "train.input"), ("data.place", "train.input"),
+             ("train.input", "train.step")]
+    dispatch = [("train.dispatch", "train.step")]
+    tail = [("train.wait", "train.step"), ("train.account", "train.step"),
+            ("train.step", None)]
+    # step k+1's input is built between step k's dispatch and its wait
+    order = (build + dispatch + build + tail + dispatch + build + tail
+             + dispatch + tail)
+    assert [(s.name, s.parent) for s in got] == order
+    assert [s.step for s in got] == [0] * 10 + [1] * 7 + [2] * 4
+    assert [s.attrs["ahead"] for s in got
+            if s.name == "train.input"] == [False, True, True]
     for a, b in zip(got, got[1:]):
         if b.parent == a.parent:            # siblings follow each other
             assert a.end_ns <= b.start_ns
