@@ -77,10 +77,51 @@ def in_scan_param_names(params) -> frozenset[str]:
     return frozenset()
 
 
+_BN_EPS = 1e-5
+_BN_AXES = (0, 1, 2)
+
+
+@jax.custom_vjp
 def _bn(x, s, b):
-    mean = jnp.mean(x, axis=(0, 1, 2), keepdims=True)
-    var = jnp.var(x, axis=(0, 1, 2), keepdims=True)
-    return (x - mean) * jax.lax.rsqrt(var + 1e-5) * s + b
+    """BatchNorm of NHWC ``x`` per channel over (N, H, W), with the
+    statistics of this worker's share of the batch and no running
+    averages.  Statistics and gradients are float32 whatever ``x``'s
+    dtype; the result comes back in it.
+
+    A custom VJP so that the memory passes are the ones the math needs:
+    one read of ``x`` for the statistics (Σx and Σx² side by side,
+    var = E[x²] - E[x]²), and in ``_bn_bwd`` one read of ``x`` and ``dy``
+    for both sums.
+    """
+    return _bn_fwd(x, s, b)[0]
+
+
+def _bn_fwd(x, s, b):
+    xf = x.astype(jnp.float32)
+    n = xf.size // xf.shape[-1]
+    mean = jnp.sum(xf, _BN_AXES) / n
+    var = jnp.maximum(jnp.sum(xf * xf, _BN_AXES) / n - mean * mean, 0.0)
+    r = jax.lax.rsqrt(var + _BN_EPS)
+    y = (xf - mean) * (r * s) + b
+    return y.astype(x.dtype), (x, mean, r, s, b)
+
+
+def _bn_bwd(res, dy):
+    """x̂ = (x - mean)·r recomputed; Σdy and Σdy·x̂ (which are db and ds)
+    in one fused reduction; dx = s·r·(dy - (Σdy + x̂·Σdy·x̂)/N).  ``b``
+    is kept only for its dtype."""
+    x, mean, r, s, b = res
+    xf = x.astype(jnp.float32)
+    n = xf.size // xf.shape[-1]
+    xhat = (xf - mean) * r
+    g = dy.astype(jnp.float32)
+    db = jnp.sum(g, _BN_AXES)
+    ds = jnp.sum(g * xhat, _BN_AXES)
+    dx = (s * r) * (g - (db + xhat * ds) / n)
+    return dx.astype(x.dtype), ds.astype(s.dtype), db.astype(b.dtype)
+
+
+_bn.defvjp(_bn_fwd, _bn_bwd)
 
 
 def _conv2d(x, w, stride=1):
